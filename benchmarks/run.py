@@ -31,6 +31,7 @@ from repro.core import (AutoscalePolicy, ProviderModel, StagedController,
 from repro.core.adaptive import Stage as CtrlStage
 from repro.configs.paper_workloads import (BC_SCALED, BC_SCALED_TASKS,
                                            MS_SCALED, UTS_SCALED)
+from repro.kernels.dispatch import enable_compile_cache
 
 ROWS = []
 JSON_ROWS = []
@@ -1149,6 +1150,7 @@ def main() -> None:
                          "(name, us_per_call, derived kv) for "
                          "cross-PR perf tracking")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only or list(BENCHES)
     print("name,us_per_call,derived")
     for name in names:

@@ -23,6 +23,7 @@ from .mariani_silver import (
     mariani_silver,
     ms_spec,
     naive_render,
+    plane_coords,
 )
 from .betweenness import (
     BCResult,
@@ -38,7 +39,7 @@ __all__ = [
     "Bag", "UTSParams", "UTSResult", "expand_bag", "expected_tree_size",
     "uts_parallel", "uts_sequential", "uts_spec",
     "Action", "MSParams", "MSResult", "Rect", "evaluate_rect",
-    "mariani_silver", "ms_spec", "naive_render",
+    "mariani_silver", "ms_spec", "naive_render", "plane_coords",
     "BCResult", "RMATParams", "bc_batch", "bc_single_node", "bc_spec",
     "betweenness_centrality", "rmat_graph",
 ]

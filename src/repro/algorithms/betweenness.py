@@ -32,6 +32,9 @@ __all__ = ["RMATParams", "rmat_graph", "bc_batch", "bc_single_node",
            "bc_spec", "betweenness_centrality", "BCResult"]
 
 _INF = np.int32(2**30)
+# f32 products at full precision: at default precision the TPU multiplies
+# in one bf16 pass, which rounds path counts sigma above 256
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -104,7 +107,7 @@ def bc_batch(adj: jax.Array, sources: jax.Array,
     def fwd_body(carry):
         level, dist, sigma, _ = carry
         frontier = (dist == level).astype(jnp.float32)          # [S, N]
-        reach = (sigma * frontier) @ adj                        # [S, N]
+        reach = jnp.dot(sigma * frontier, adj, precision=_EXACT)  # [S, N]
         unvisited = dist == _INF
         newfront = jnp.logical_and(unvisited, reach > 0)
         dist = jnp.where(newfront, level + 1, dist)
@@ -121,7 +124,7 @@ def bc_batch(adj: jax.Array, sources: jax.Array,
         lvl, delta = carry
         w_mask = (dist == lvl).astype(jnp.float32)
         coeff = w_mask * (1.0 + delta) / safe_sigma             # [S, N]
-        back = coeff @ adj.T                                    # [S, N]
+        back = jnp.dot(coeff, adj.T, precision=_EXACT)          # [S, N]
         v_mask = (dist == lvl - 1).astype(jnp.float32)
         delta = delta + v_mask * sigma * back
         return lvl - 1, delta

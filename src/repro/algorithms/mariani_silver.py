@@ -21,15 +21,16 @@ from enum import Enum
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..core import Pool, TaskShape, WorkSpec, run_irregular
 from ..kernels.mandelbrot.ops import mandelbrot
-from ..kernels.mandelbrot.ref import coords
+from ..kernels.mandelbrot.ref import coords, mandelbrot_ref
 
 __all__ = ["MSParams", "Rect", "Action", "RectResult", "ms_spec",
            "evaluate_rect", "evaluate_rects", "mariani_silver",
-           "naive_render", "MSResult"]
+           "naive_render", "plane_coords", "MSResult"]
 
 
 @dataclass(frozen=True)
@@ -301,8 +302,17 @@ def mariani_silver(executor: Pool, p: MSParams) -> MSResult:
     )
 
 
+_render_ref = jax.jit(mandelbrot_ref, static_argnums=2)
+
+
+def plane_coords(p: MSParams):
+    """Coordinates of every pixel center of the image, as [H, W] arrays."""
+    return _pixel_coords(Rect(0, 0, p.width, p.height, 0), p)
+
+
 def naive_render(p: MSParams) -> np.ndarray:
-    """Escape-time over every pixel — the correctness oracle."""
-    full = Rect(0, 0, p.width, p.height, 0)
-    c_re, c_im = _pixel_coords(full, p)
-    return np.asarray(mandelbrot(c_re, c_im, p.max_dwell))
+    """Escape-time over every pixel — the correctness oracle.
+
+    Runs the pure-jnp reference, not the dispatched kernel, so on the
+    chip it stays independent of the Pallas kernel under test."""
+    return np.asarray(_render_ref(*plane_coords(p), p.max_dwell))

@@ -36,7 +36,9 @@ The three shipped ops — ``uts_hash``, ``mandelbrot``,
 """
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple, Union
 
 import jax
@@ -46,6 +48,7 @@ __all__ = [
     "KernelOp", "register_kernel", "get_kernel", "registered_kernels",
     "dispatch", "bucket", "resolve_backend", "on_tpu",
     "compile_log", "reset_compile_log", "estimate_cost",
+    "enable_compile_cache",
 ]
 
 #: canonical backend names, in resolution-priority order
@@ -58,7 +61,12 @@ def on_tpu() -> bool:
 
 
 def resolve_backend(backend: Optional[str]) -> str:
-    """Canonical backend name; ``None`` = auto (tpu-pallas on TPU, else ref)."""
+    """Canonical backend name; ``None`` = auto (tpu-pallas on TPU, else ref).
+
+    The auto choice falls back to ``ref`` without a word when no TPU is
+    found, which is what the CPU test suite relies on.  A run meant for
+    the chip therefore checks :func:`compile_log` afterwards: every entry
+    of its kernels must carry the ``tpu-pallas`` backend."""
     if backend is None:
         return "tpu-pallas" if on_tpu() else "ref"
     backend = _ALIASES.get(backend, backend)
@@ -189,6 +197,27 @@ def reset_compile_log(name: Optional[str] = None) -> None:
         _COMPILE_LOG.clear()
     else:
         _COMPILE_LOG.pop(name, None)
+
+
+#: where the persistent compilation cache lives when the environment
+#: names no directory: one fixed path inside the checkout
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`, which never depends on a temporary name, a
+    process id or the time, so a later run finds what an earlier one
+    compiled.  Entry points call this; importing the library does not.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def estimate_cost(op: Union[str, KernelOp], *args: Any) -> float:

@@ -35,7 +35,9 @@ def _mandelbrot_kernel(c_re_ref, c_im_ref, dwell_ref, *, max_iter: int):
     c_im = c_im_ref[...]
     z_re0 = jnp.zeros_like(c_re)
     z_im0 = jnp.zeros_like(c_im)
-    dwell0 = jnp.zeros(c_re.shape, jnp.int32)
+    # derived from the loaded tile, not a splat constant: Mosaic cannot
+    # relayout a replicated int32 splat into the while_loop's carry
+    dwell0 = (c_re * 0.0).astype(jnp.int32)
 
     def cond(carry):
         i, _, _, _, any_active = carry

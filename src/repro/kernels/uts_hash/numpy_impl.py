@@ -13,7 +13,8 @@ import math
 
 import numpy as np
 
-__all__ = ["uts_child_digests_np", "geometric_children_np"]
+__all__ = ["uts_child_digests_np", "geometric_children_np",
+           "geometric_thresholds"]
 
 _H0 = (0x67452301, 0xEFCDAB89, 0x98BADCFE, 0x10325476, 0xC3D2E1F0)
 _K = (0x5A827999, 0x6ED9EBA1, 0x8F1BBCDC, 0xCA62C1D6)
@@ -65,13 +66,26 @@ def uts_child_digests_np(parent: np.ndarray, child_ix: np.ndarray) -> np.ndarray
         np.seterr(**old)
 
 
+def geometric_thresholds(b0: float, max_children: int) -> np.ndarray:
+    """u31 thresholds of the Geometric(mean b0) child count -> [K] int32.
+
+    With u = (u31 + 1) / (2^31 + 1) and q = 1 - p = b0 / (1 + b0), the
+    count m = floor(log(u) / log(q)) is at least k exactly when u <= q^k,
+    that is when u31 <= floor(q^k (2^31 + 1)) - 1 =: t[k-1].  The table
+    is built once in float64 on the host; the count itself is then
+    integer comparisons only, which every backend evaluates the same.
+    """
+    q = b0 / (1.0 + b0)
+    t = [math.floor(q ** k * 2147483649.0) - 1
+         for k in range(1, max_children + 1)]
+    return np.clip(np.asarray(t, np.int64), -1, 2**31 - 1).astype(np.int32)
+
+
 def geometric_children_np(digest: np.ndarray, depth: np.ndarray, *,
                           b0: float = 4.0, max_depth: int = 18,
                           max_children: int = 64) -> np.ndarray:
-    """Numpy twin of ops.geometric_children (same u31 -> Geometric map)."""
-    u31 = (digest[0] >> np.uint32(1)).astype(np.int64).astype(np.float32)
-    u = (u31 + 1.0) / (2147483648.0 + 1.0)
-    p = 1.0 / (1.0 + b0)
-    m = np.floor(np.log(u) / math.log(1.0 - p)).astype(np.int32)
-    m = np.clip(m, 0, max_children)
+    """Numpy twin of ops.geometric_children (same thresholds)."""
+    u31 = (digest[0] >> np.uint32(1)).astype(np.int32)
+    t = geometric_thresholds(b0, max_children)
+    m = np.sum(u31[:, None] <= t[None, :], axis=1, dtype=np.int32)
     return np.where(depth >= max_depth, 0, m).astype(np.int32)

@@ -15,13 +15,13 @@ digest:
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
 from ..dispatch import KernelOp, dispatch, register_kernel
 from .kernel import DEFAULT_BLOCK_N, uts_hash_pallas
+from .numpy_impl import geometric_thresholds
 from .ref import uts_child_digests_ref
 
 __all__ = [
@@ -93,15 +93,16 @@ def geometric_children(digest: jax.Array, depth: jax.Array, *,
                        max_children: int = 64) -> jax.Array:
     """Number of children per node, Geometric(mean=b0), 0 past cutoff.
 
-    m = floor(log(u) / log(1 - p)) with p = 1/(1+b0) gives a geometric
-    variable on {0,1,...} with mean b0 (the UTS GEO shape function).
-    ``max_children`` clamps the tail so frontier buffers stay bounded
-    (P(m > 64) ~ (4/5)^64 ~ 6e-7 at b0=4).
+    m = floor(log(u) / log(1 - p)) with p = 1/(1+b0) and u the digest's
+    31-bit uniform mapped into (0, 1) gives a geometric variable on
+    {0,1,...} with mean b0 (the UTS GEO shape function).  It is counted
+    exactly, as the thresholds of ``geometric_thresholds`` that u31 does
+    not exceed: integer comparisons, so the chip, XLA on the CPU and the
+    numpy twin grow the same tree (a float32 ``log`` differs between them
+    near integer boundaries).  ``max_children`` clamps the tail so
+    frontier buffers stay bounded (P(m > 64) ~ (4/5)^64 ~ 6e-7 at b0=4).
     """
-    u31 = random_u31(digest).astype(jnp.float32)
-    # map to open interval (0, 1): (r + 1) / (2^31 + 1)
-    u = (u31 + 1.0) / (2147483648.0 + 1.0)
-    p = 1.0 / (1.0 + b0)
-    m = jnp.floor(jnp.log(u) / math.log(1.0 - p)).astype(jnp.int32)
-    m = jnp.clip(m, 0, max_children)
+    t = jnp.asarray(geometric_thresholds(b0, max_children))
+    u31 = random_u31(digest)
+    m = jnp.sum(u31[:, None] <= t[None, :], axis=1, dtype=jnp.int32)
     return jnp.where(depth >= max_depth, 0, m)

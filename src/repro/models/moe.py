@@ -26,10 +26,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .config import MoEConfig

@@ -2,13 +2,16 @@
 padding round-trips for all three registered ops, and the O(log)
 recompilation bound the bucketing policy exists to enforce."""
 import math
+from pathlib import Path
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.dispatch import (KernelOp, bucket, compile_log,
-                                    dispatch, estimate_cost, get_kernel,
+from repro.kernels.dispatch import (COMPILE_CACHE_DIR, KernelOp, bucket,
+                                    compile_log, dispatch,
+                                    enable_compile_cache, estimate_cost,
+                                    get_kernel,
                                     register_kernel, registered_kernels,
                                     reset_compile_log, resolve_backend)
 from repro.kernels.flash_attention.ops import flash_attention_fused
@@ -39,6 +42,36 @@ def test_resolve_backend():
     assert resolve_backend(None) in ("tpu-pallas", "ref")
     with pytest.raises(ValueError, match="unknown backend"):
         resolve_backend("cuda")
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore JAX's compile-cache directory after the test."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_from_environment(cache_dir_config, monkeypatch,
+                                        tmp_path):
+    # the environment names the directory: JAX reads it, code sets none
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = cache_dir_config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert cache_dir_config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_fixed_path_in_checkout(cache_dir_config,
+                                              monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert enable_compile_cache() == str(COMPILE_CACHE_DIR)
+    assert cache_dir_config.jax_compilation_cache_dir == \
+        str(COMPILE_CACHE_DIR)
+    root = Path(__file__).resolve().parents[1]
+    assert COMPILE_CACHE_DIR == root / ".jax_cache"
+    # the same directory on every call, in every process
+    assert enable_compile_cache() == str(COMPILE_CACHE_DIR)
 
 
 def test_bucket_policy():

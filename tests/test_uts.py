@@ -47,6 +47,25 @@ def test_branching_mean_close_to_b0():
     assert int(m.min()) >= 0
 
 
+def test_geometric_children_jnp_matches_numpy():
+    """The jnp child count (the chip's) equals the numpy twin (the host
+    reference's) bit for bit: a one-ULP difference in the float32 log
+    near an integer boundary would grow a different tree."""
+    import jax.numpy as jnp
+    from repro.kernels.uts_hash.ops import geometric_children
+    rng = np.random.RandomState(11)
+    n = 1 << 21
+    digests = rng.randint(0, 2**32, size=(5, n),
+                          dtype=np.uint64).astype(np.uint32)
+    depths = rng.randint(0, 20, size=n).astype(np.int32)
+    want = geometric_children_np(digests, depths, b0=4.0, max_depth=18)
+    got = np.asarray(geometric_children(jnp.asarray(digests),
+                                        jnp.asarray(depths),
+                                        b0=4.0, max_depth=18))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+
+
 def test_depth_cutoff_terminates():
     digests = np.random.RandomState(0).randint(
         0, 2**31, size=(5, 100)).astype(np.uint32)

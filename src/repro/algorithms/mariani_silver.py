@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core import Pool, TaskShape, WorkSpec, run_irregular
+from ..core.telemetry import span
+from ..kernels.dispatch import to_device, to_host
 from ..kernels.mandelbrot.ops import mandelbrot
 from ..kernels.mandelbrot.ref import coords, mandelbrot_ref
 
@@ -86,7 +88,7 @@ def _pixel_coords(rect: Rect, p: MSParams):
     xs = p.x0 + (np.arange(rect.px0, rect.px1) + 0.5) * sx
     ys = p.y0 + (np.arange(rect.py0, rect.py1) + 0.5) * sy
     c_im, c_re = np.meshgrid(ys, xs, indexing="ij")
-    return jnp.asarray(c_re, jnp.float32), jnp.asarray(c_im, jnp.float32)
+    return to_device(c_re, jnp.float32), to_device(c_im, jnp.float32)
 
 
 def _border_coords(rect: Rect, p: MSParams):
@@ -105,9 +107,10 @@ def _border_coords(rect: Rect, p: MSParams):
 
 def _border_dwells(rect: Rect, p: MSParams) -> np.ndarray:
     """Dwells of the rectangle's border pixels (flattened)."""
-    bre, bim = _border_coords(rect, p)
-    return np.asarray(mandelbrot(bre[None, :], bim[None, :],
-                                 p.max_dwell))[0]
+    with span("ms.coords"):
+        bre, bim = _border_coords(rect, p)
+        bre, bim = bre[None, :], bim[None, :]
+    return to_host(mandelbrot(bre, bim, p.max_dwell))[0]
 
 
 def _classify(rect: Rect, border: np.ndarray,
@@ -124,10 +127,13 @@ def _classify(rect: Rect, border: np.ndarray,
 
 def evaluate_rect(rect: Rect, p: MSParams) -> RectResult:
     """Task body — paper Listing 3 (``Callable.call``)."""
-    res = _classify(rect, _border_dwells(rect, p), p)
+    border = _border_dwells(rect, p)
+    with span("ms.classify"):
+        res = _classify(rect, border, p)
     if res.action is Action.SET_DWELL_ARRAY:
-        c_re, c_im = _pixel_coords(rect, p)
-        res.dwell_array = np.asarray(mandelbrot(c_re, c_im, p.max_dwell))
+        with span("ms.coords"):
+            c_re, c_im = _pixel_coords(rect, p)
+        res.dwell_array = to_host(mandelbrot(c_re, c_im, p.max_dwell))
     return res
 
 
@@ -139,31 +145,35 @@ def evaluate_rects(rects: List[Rect], p: MSParams) -> List[RectResult]:
     per-rect results are bit-identical to :func:`evaluate_rect`."""
     if not rects:
         return []
-    borders = [_border_coords(r, p) for r in rects]
-    lens = [int(b[0].shape[0]) for b in borders]
-    bre = jnp.concatenate([b[0] for b in borders])[None, :]
-    bim = jnp.concatenate([b[1] for b in borders])[None, :]
-    dwells = np.asarray(mandelbrot(bre, bim, p.max_dwell))[0]
-    results: List[RectResult] = []
-    off = 0
-    for rect, n in zip(rects, lens):
-        results.append(_classify(rect, dwells[off:off + n], p))
-        off += n
-    leaves = [r for r in results if r.action is Action.SET_DWELL_ARRAY]
-    if leaves:
-        flats = []
-        for res in leaves:
-            c_re, c_im = _pixel_coords(res.rect, p)
-            flats.append((c_re.ravel(), c_im.ravel()))
-        fre = jnp.concatenate([f[0] for f in flats])[None, :]
-        fim = jnp.concatenate([f[1] for f in flats])[None, :]
-        flat_dwell = np.asarray(mandelbrot(fre, fim, p.max_dwell))[0]
+    with span("ms.coords"):
+        borders = [_border_coords(r, p) for r in rects]
+        lens = [int(b[0].shape[0]) for b in borders]
+        bre = jnp.concatenate([b[0] for b in borders])[None, :]
+        bim = jnp.concatenate([b[1] for b in borders])[None, :]
+    dwells = to_host(mandelbrot(bre, bim, p.max_dwell))[0]
+    with span("ms.classify"):
+        results: List[RectResult] = []
         off = 0
-        for res in leaves:
-            r = res.rect
-            res.dwell_array = \
-                flat_dwell[off:off + r.w * r.h].reshape(r.h, r.w)
-            off += r.w * r.h
+        for rect, n in zip(rects, lens):
+            results.append(_classify(rect, dwells[off:off + n], p))
+            off += n
+        leaves = [r for r in results if r.action is Action.SET_DWELL_ARRAY]
+    if leaves:
+        with span("ms.coords"):
+            flats = []
+            for res in leaves:
+                c_re, c_im = _pixel_coords(res.rect, p)
+                flats.append((c_re.ravel(), c_im.ravel()))
+            fre = jnp.concatenate([f[0] for f in flats])[None, :]
+            fim = jnp.concatenate([f[1] for f in flats])[None, :]
+        flat_dwell = to_host(mandelbrot(fre, fim, p.max_dwell))[0]
+        with span("ms.assemble"):
+            off = 0
+            for res in leaves:
+                r = res.rect
+                res.dwell_array = \
+                    flat_dwell[off:off + r.w * r.h].reshape(r.h, r.w)
+                off += r.w * r.h
     return results
 
 
@@ -315,4 +325,4 @@ def naive_render(p: MSParams) -> np.ndarray:
 
     Runs the pure-jnp reference, not the dispatched kernel, so on the
     chip it stays independent of the Pallas kernel under test."""
-    return np.asarray(_render_ref(*plane_coords(p), p.max_dwell))
+    return to_host(_render_ref(*plane_coords(p), p.max_dwell))

@@ -29,7 +29,6 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 import numpy as np
-import jax.numpy as jnp
 
 from ..core import (
     Pool,
@@ -38,9 +37,8 @@ from ..core import (
     WorkSpec,
     run_irregular,
 )
-import jax
-
-from ..kernels.dispatch import bucket
+from ..core.telemetry import span
+from ..kernels.dispatch import bucket, on_tpu, to_device, to_host
 from ..kernels.uts_hash.ops import (
     geometric_children,
     root_digest,
@@ -81,8 +79,9 @@ class Bag:
 
     @staticmethod
     def root(params: UTSParams) -> "Bag":
-        d = np.asarray(root_digest(params.seed))
-        return Bag(d, np.zeros((1,), np.int32))
+        with span("uts.root"):
+            d = to_host(root_digest(params.seed))
+            return Bag(d, np.zeros((1,), np.int32))
 
     def split(self, k: int) -> List["Bag"]:
         """Resize into <= k sub-bags (paper's ``resizeBag``)."""
@@ -109,48 +108,56 @@ def _expand_generation(digests: np.ndarray, depths: np.ndarray,
     Both jitted stages are padded to *fixed* bucket sizes derived from
     ``params.chunk`` so an entire traversal compiles O(1) graphs (the
     frontier size is irregular by construction; without this every
-    generation would recompile).
+    generation would recompile).  On the chip the host work between
+    the kernel calls runs in ``uts.frontier`` spans.
     """
     n = depths.shape[0]
     if n == 0:
         return np.zeros((5, 0), np.uint32), np.zeros((0,), np.int32)
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        # bucket-pad -> bounded set of compiled kernels; padding rows sit
-        # at max_depth and thus produce zero children.
-        nb = bucket(n, floor=min(params.chunk, 4096))
-        dig_p = np.pad(digests, ((0, 0), (0, nb - n)))
-        dep_p = np.pad(depths, (0, nb - n),
-                       constant_values=params.max_depth)
-        counts = np.asarray(
-            geometric_children(jnp.asarray(dig_p), jnp.asarray(dep_p),
-                               b0=params.b0,
-                               max_depth=params.max_depth))[:n]
+    chip = on_tpu()
+    if chip:
+        with span("uts.frontier"):
+            # bucket-pad -> bounded set of compiled kernels; padding rows
+            # sit at max_depth and thus produce zero children.
+            nb = bucket(n, floor=min(params.chunk, 4096))
+            dig_p = to_device(np.pad(digests, ((0, 0), (0, nb - n))))
+            dep_p = to_device(np.pad(depths, (0, nb - n),
+                                     constant_values=params.max_depth))
+        with span("dispatch.geometric_children"):
+            counts_d = geometric_children(dig_p, dep_p, b0=params.b0,
+                                          max_depth=params.max_depth)
+        counts = to_host(counts_d)[:n]
     else:
         counts = geometric_children_np(digests, depths, b0=params.b0,
                                        max_depth=params.max_depth)
-    total = int(counts.sum())
-    if total == 0:
-        return np.zeros((5, 0), np.uint32), np.zeros((0,), np.int32)
-    parent_ix = np.repeat(np.arange(n), counts)
-    # child index within each parent: 0..m_i-1
-    offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    child_ix = (np.arange(total) - offsets[parent_ix]).astype(np.uint32)
-    parents = digests[:, parent_ix]
-    if not on_tpu:
-        children = uts_child_digests_np(parents, child_ix)
-        return children, (depths[parent_ix] + 1).astype(np.int32)
-    # TPU: hash in fixed-size slices -> single compiled Pallas dispatch
+    with span("uts.frontier"):
+        total = int(counts.sum())
+        if total == 0:
+            return np.zeros((5, 0), np.uint32), np.zeros((0,), np.int32)
+        parent_ix = np.repeat(np.arange(n), counts)
+        # child index within each parent: 0..m_i-1
+        offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        child_ix = (np.arange(total) - offsets[parent_ix]).astype(np.uint32)
+        parents = digests[:, parent_ix]
+        child_depths = (depths[parent_ix] + 1).astype(np.int32)
+    if not chip:
+        return uts_child_digests_np(parents, child_ix), child_depths
+    # chip: hash in fixed-size slices -> single compiled Pallas dispatch
     hb = 4 * min(params.chunk, 4096)
     outs = []
     for s in range(0, total, hb):
         e = min(s + hb, total)
-        par = np.pad(parents[:, s:e], ((0, 0), (0, hb - (e - s))))
-        cix = np.pad(child_ix[s:e], (0, hb - (e - s)))
-        outs.append(np.asarray(uts_child_digests(
-            jnp.asarray(par), jnp.asarray(cix)))[:, :e - s])
-    children = np.concatenate(outs, axis=1)
-    return children, (depths[parent_ix] + 1).astype(np.int32)
+        with span("uts.frontier"):
+            par = to_device(np.pad(parents[:, s:e],
+                                   ((0, 0), (0, hb - (e - s)))))
+            cix = to_device(np.pad(child_ix[s:e], (0, hb - (e - s))))
+        outs.append(to_host(uts_child_digests(par, cix,
+                                              kept=e - s))[:, :e - s])
+    with span("uts.frontier"):
+        # free the generation's device buffers here rather than at the
+        # return: a buffer's release can wait on the interpreter lock
+        del dig_p, dep_p, counts_d, par, cix
+        return np.concatenate(outs, axis=1), child_depths
 
 
 def expand_bag(bag: Bag, iters: int,
@@ -165,14 +172,16 @@ def expand_bag(bag: Bag, iters: int,
     count = 0
     stack = bag
     while count < iters and stack.size:
-        budget = iters - count
-        take = min(stack.size, budget, params.chunk)
-        head = Bag(stack.digests[:, -take:], stack.depths[-take:])
-        rest = Bag(stack.digests[:, :-take], stack.depths[:-take])
-        count += take
+        with span("uts.merge"):
+            budget = iters - count
+            take = min(stack.size, budget, params.chunk)
+            head = Bag(stack.digests[:, -take:], stack.depths[-take:])
+            rest = Bag(stack.digests[:, :-take], stack.depths[:-take])
+            count += take
         children, depths = _expand_generation(head.digests, head.depths,
                                               params)
-        stack = Bag.merge([rest, Bag(children, depths)])
+        with span("uts.merge"):
+            stack = Bag.merge([rest, Bag(children, depths)])
     return count, stack
 
 

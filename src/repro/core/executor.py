@@ -57,7 +57,8 @@ from .pool import Pool, register_pool
 from .provider import Backoff, ContainerFleet, ProviderModel
 from .telemetry import (CANCEL, CAPACITY_GROW, CAPACITY_SHRINK,
                         COLD_START, COMPLETE, REQUEUE, START, SUBMIT,
-                        THROTTLED, WORKER_KILLED, Clock, EventLog)
+                        THROTTLED, WORKER_KILLED, Clock, EventLog, current,
+                        span)
 
 __all__ = [
     "ConcurrencyTracker",
@@ -455,7 +456,9 @@ class BaseExecutor(Pool):
                     batch=getattr(task.fn, "_repro_is_batch", False)):
                 raise WorkerKilledError(
                     f"injected container death on {worker}")
-            result = task.run()
+            with span("pool.task", task=task.task_id,
+                      parent=task.span_parent):
+                result = task.run()
         except BaseException as exc:  # noqa: BLE001 — report any failure
             task.end_time = time.monotonic()
             killed = isinstance(exc, WorkerKilledError)
@@ -516,7 +519,8 @@ class BaseExecutor(Pool):
             raise FunctionThrottledError(
                 f"{self.name}: concurrency limit {self.max_concurrency} reached")
         self._ensure_workers()
-        task = Task(fn=fn, args=args, kwargs=kwargs, cost_hint=cost_hint)
+        task = Task(fn=fn, args=args, kwargs=kwargs, cost_hint=cost_hint,
+                    span_parent=current())
         future = ElasticFuture(task)
         self.stats.on_submit(task.task_id, parent=parent)
         self._queue.put((task, future))

@@ -156,6 +156,9 @@ class Task:
     end_time: Optional[float] = None
     worker: Optional[str] = None
     attempts: int = 0
+    #: the submitting thread's open span (``telemetry.current()``), the
+    #: parent of the span the worker runs the task under
+    span_parent: Optional[tuple] = None
 
     def run(self) -> Any:
         return self.fn(*self.args, **self.kwargs)

@@ -35,7 +35,7 @@ from .futures import CompletionQueue, ElasticFuture, TaskState
 from .pool import Pool
 from .provider import AutoscalePolicy
 from .telemetry import (CHECKPOINT, FOLDED, PARENT_ROOT, REQUEUE,
-                        WORKER_KILLED)
+                        WORKER_KILLED, span)
 
 __all__ = ["WorkSpec", "IrregularResult", "run_irregular"]
 
@@ -375,6 +375,35 @@ def run_irregular(
                             autoscale=autoscale, timeout=timeout,
                             batching=batching, resume_from=resume_from,
                             wal=wal)
+    with span("master.job", spec=spec.name):
+        return _run_single(
+            pool, spec, shape=shape, initial_shape=initial_shape,
+            controller=controller, autoscale=autoscale,
+            speculative_deadline=speculative_deadline, timeout=timeout,
+            batching=batching, arrivals=arrivals, resume_from=resume_from,
+            wal=wal, checkpoint_every=checkpoint_every)
+
+
+def _run_single(
+    pool: Pool,
+    spec: WorkSpec,
+    *,
+    shape: Optional[TaskShape],
+    initial_shape: Optional[TaskShape],
+    controller: Optional[Any],
+    autoscale: Optional[AutoscalePolicy],
+    speculative_deadline: Optional[float],
+    timeout: Optional[float],
+    batching: Optional[bool],
+    arrivals: Optional[Iterable[Tuple[float, Any]]],
+    resume_from: Optional[Any],
+    wal: Optional[bool],
+    checkpoint_every: Optional[int],
+) -> IrregularResult:
+    """The single-master loop of :func:`run_irregular`, inside its
+    ``master.job`` span: ``master.seed``, then ``master.dispatch`` for
+    each wave submitted, ``master.wait`` on the completion queue,
+    ``master.fold`` for each result, ``master.finish`` at the end."""
     t0 = time.monotonic()
     shape = shape or spec.shape
     if batching and spec.execute_batch is None:
@@ -475,17 +504,22 @@ def run_irregular(
                 f"journaled)")
     elif resume_from is not None:
         from ..chaos.recovery import recover_frontier
-        rec = recover_frontier(resume_from, spec, shape=shape,
-                               initial_shape=initial_shape)
+        with span("master.seed"):
+            rec = recover_frontier(resume_from, spec, shape=shape,
+                                   initial_shape=initial_shape)
         state = rec.partial
         recovered = len(rec.pending)
         # recovered items dispatch at the steady shape: the paper
         # specs' outputs are granularity-insensitive, the same
         # property shards=K bit-identity rests on
-        dispatch_ready(list(rec.pending), shape, parent=PARENT_ROOT)
+        with span("master.dispatch"):
+            dispatch_ready(list(rec.pending), shape, parent=PARENT_ROOT)
     else:
-        dispatch_ready(list(spec.seed(initial_shape or shape)),
-                       initial_shape or shape, parent=PARENT_ROOT)
+        with span("master.seed"):
+            seeds = list(spec.seed(initial_shape or shape))
+        with span("master.dispatch"):
+            dispatch_ready(seeds, initial_shape or shape,
+                           parent=PARENT_ROOT)
 
     deadline = None if timeout is None else t0 + timeout
     speculated = 0
@@ -585,9 +619,11 @@ def run_irregular(
             # re-acquiring per completion.  Open-loop arrivals keep
             # max_items=1 so arrival releases interleave with
             # completions at exactly the recorded instants.
-            batch = cq.drain(
-                max_items=1 if pending_arrivals is not None else None,
-                timeout=wait)
+            with span("master.wait"):
+                batch = cq.drain(
+                    max_items=(1 if pending_arrivals is not None
+                               else None),
+                    timeout=wait)
         except TimeoutError:
             if speculative_deadline is not None:
                 scan_stragglers()
@@ -597,46 +633,48 @@ def run_irregular(
             # deadlines on the completion path too, not only when idle
             scan_stragglers()
         for f in batch:
-            d = outstanding.pop(f)
-            result = f.result()
-            state = spec.reduce(state, result)
-            if controller is not None:
-                shape = controller.update(len(outstanding))
-            # child waves to issue once WAL order allows: (kids, parent)
-            ready: List[Tuple[List[Any], int]] = []
-            if wal_log is not None:
-                # WAL order: journal AFTER the fold applies and BEFORE
-                # any child dispatch — recovery replays exactly the
-                # folds that happened and re-derives everything else.
-                # Fused-batch slots accumulate into one atomic entry
-                # (see _ChunkWal), and their children are deferred with
-                # it: on wall pools a chunk's slots settle across drain
-                # batches, and a child folded before its parent chunk's
-                # event would leave a crash window whose journal
-                # records folds the replayed seed/split never produced.
-                entry = {"item": spec.encode_item(d.item),
-                         "result": spec.encode_result(result)}
-                if d.chunk is None:
-                    wal_log.emit(FOLDED, task_id=f._task.task_id,
-                                 payload=entry)
-                    folds_since += 1
+            with span("master.fold"):
+                d = outstanding.pop(f)
+                result = f.result()
+                state = spec.reduce(state, result)
+                if controller is not None:
+                    shape = controller.update(len(outstanding))
+                # child waves to issue once WAL order allows: (kids, parent)
+                ready: List[Tuple[List[Any], int]] = []
+                if wal_log is not None:
+                    # WAL order: journal AFTER the fold applies and BEFORE
+                    # any child dispatch — recovery replays exactly the
+                    # folds that happened and re-derives everything else.
+                    # Fused-batch slots accumulate into one atomic entry
+                    # (see _ChunkWal), and their children are deferred with
+                    # it: on wall pools a chunk's slots settle across drain
+                    # batches, and a child folded before its parent chunk's
+                    # event would leave a crash window whose journal
+                    # records folds the replayed seed/split never produced.
+                    entry = {"item": spec.encode_item(d.item),
+                             "result": spec.encode_result(result)}
+                    if d.chunk is None:
+                        wal_log.emit(FOLDED, task_id=f._task.task_id,
+                                     payload=entry)
+                        folds_since += 1
+                        ready.append((list(spec.split(result, shape)),
+                                      f._task.task_id))
+                    else:
+                        d.chunk.entries.append(entry)
+                        d.chunk.deferred.append(
+                            (list(spec.split(result, shape)),
+                             f._task.task_id))
+                        if len(d.chunk.entries) == d.chunk.size:
+                            wal_log.emit(FOLDED, task_id=f._task.task_id,
+                                         payload={"batch": d.chunk.entries})
+                            folds_since += d.chunk.size
+                            ready.extend(d.chunk.deferred)
+                else:
                     ready.append((list(spec.split(result, shape)),
                                   f._task.task_id))
-                else:
-                    d.chunk.entries.append(entry)
-                    d.chunk.deferred.append(
-                        (list(spec.split(result, shape)),
-                         f._task.task_id))
-                    if len(d.chunk.entries) == d.chunk.size:
-                        wal_log.emit(FOLDED, task_id=f._task.task_id,
-                                     payload={"batch": d.chunk.entries})
-                        folds_since += d.chunk.size
-                        ready.extend(d.chunk.deferred)
-            else:
-                ready.append((list(spec.split(result, shape)),
-                              f._task.task_id))
-            for kids, pid in ready:
-                dispatch_ready(kids, shape, parent=pid)
+            with span("master.dispatch"):
+                for kids, pid in ready:
+                    dispatch_ready(kids, shape, parent=pid)
             if (checkpoint_every is not None
                     and folds_since >= checkpoint_every
                     and not any(dd.chunk is not None and dd.chunk.entries
@@ -668,57 +706,59 @@ def run_irregular(
             if autoscale is not None:
                 apply_autoscale()
 
-    snap = pool.snapshot()
-    wall = time.monotonic() - t0
-    # sim pools bill/plot in virtual time (elapsed this run); real
-    # pools in wall time
-    vt = getattr(pool, "virtual_time_s", None)
-    makespan = (vt - vt0) if vt is not None else wall
-    cost = None
-    cold_starts = snap.get("cold_starts", 0)
-    retries = worker_deaths = 0
-    concurrency_series: List[tuple] = []
-    capacity_series: List[tuple] = []
-    if has_events:
-        # this run's events: when nothing but capacity announcements
-        # precede the run (every fresh pool emits one at construction),
-        # the window IS the log — spill-backed stores then serve the
-        # series from their incremental analytics in O(answer) instead
-        # of re-streaming a tail view per read
-        log = pool.events
-        window = (log if _prefix_is_capacity_only(log, events_start)
-                  else log.tail(events_start))
-        cost = serverless_cost(window, wall_time_s=makespan,
-                               provider=getattr(pool, "provider", None))
-        concurrency_series = window.concurrency_series()
-        capacity_series = window.capacity_series()
-        cold_starts = window.cold_starts()
-        ev_counts = window.counts()
-        retries = ev_counts.get(REQUEUE, 0)
-        worker_deaths = ev_counts.get(WORKER_KILLED, 0)
-    dag = getattr(spec, "dag", None)
-    return IrregularResult(
-        output=spec.finalize(state),
-        wall_time_s=wall,
-        tasks=n_dispatched,
-        peak_concurrency=snap.get("peak_concurrency", 0),
-        controller_transitions=list(getattr(controller, "transitions", [])),
-        speculated=speculated,
-        pool_snapshot=snap,
-        makespan_s=makespan,
-        cost=cost,
-        concurrency_series=concurrency_series,
-        capacity_series=capacity_series,
-        cold_starts=cold_starts,
-        autoscale_decisions=(list(autoscale.resize_log)
-                             if autoscale is not None else []),
-        retries=retries,
-        worker_deaths=worker_deaths,
-        recovered_tasks=recovered,
-        critical_path_len=dag.critical_path_len if dag is not None else 0,
-        stage_widths=list(dag.stage_widths) if dag is not None else [],
-        dag_nodes=dag.executed if dag is not None else 0,
-    )
+    with span("master.finish"):
+        snap = pool.snapshot()
+        wall = time.monotonic() - t0
+        # sim pools bill/plot in virtual time (elapsed this run); real
+        # pools in wall time
+        vt = getattr(pool, "virtual_time_s", None)
+        makespan = (vt - vt0) if vt is not None else wall
+        cost = None
+        cold_starts = snap.get("cold_starts", 0)
+        retries = worker_deaths = 0
+        concurrency_series: List[tuple] = []
+        capacity_series: List[tuple] = []
+        if has_events:
+            # this run's events: when nothing but capacity announcements
+            # precede the run (every fresh pool emits one at construction),
+            # the window IS the log — spill-backed stores then serve the
+            # series from their incremental analytics in O(answer) instead
+            # of re-streaming a tail view per read
+            log = pool.events
+            window = (log if _prefix_is_capacity_only(log, events_start)
+                      else log.tail(events_start))
+            cost = serverless_cost(window, wall_time_s=makespan,
+                                   provider=getattr(pool, "provider", None))
+            concurrency_series = window.concurrency_series()
+            capacity_series = window.capacity_series()
+            cold_starts = window.cold_starts()
+            ev_counts = window.counts()
+            retries = ev_counts.get(REQUEUE, 0)
+            worker_deaths = ev_counts.get(WORKER_KILLED, 0)
+        dag = getattr(spec, "dag", None)
+        return IrregularResult(
+            output=spec.finalize(state),
+            wall_time_s=wall,
+            tasks=n_dispatched,
+            peak_concurrency=snap.get("peak_concurrency", 0),
+            controller_transitions=list(getattr(controller, "transitions",
+                                                [])),
+            speculated=speculated,
+            pool_snapshot=snap,
+            makespan_s=makespan,
+            cost=cost,
+            concurrency_series=concurrency_series,
+            capacity_series=capacity_series,
+            cold_starts=cold_starts,
+            autoscale_decisions=(list(autoscale.resize_log)
+                                 if autoscale is not None else []),
+            retries=retries,
+            worker_deaths=worker_deaths,
+            recovered_tasks=recovered,
+            critical_path_len=dag.critical_path_len if dag is not None else 0,
+            stage_widths=list(dag.stage_widths) if dag is not None else [],
+            dag_nodes=dag.executed if dag is not None else 0,
+        )
 
 
 def _steal_half(frontiers: List[deque], thief: int) -> Optional[int]:

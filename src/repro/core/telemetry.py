@@ -47,13 +47,23 @@ Now there is a single source of truth:
 bounded-memory recording at scale, use the ring-buffer + JSONL-spill
 subclass :class:`repro.trace.store.TraceStore` (every pool accepts it
 via the ``trace=`` constructor keyword).
+
+Beside the timeline sits a recorder of spans and counters inside the
+program (:func:`span`, :func:`count`), off unless :func:`enable` turns
+it on.  Its spans are stamped by the same ``time.monotonic`` as the
+``WallClock`` timeline, so a task's ``pool.task`` span lies inside its
+``start`` -> ``complete`` events, and each span is also entered as a
+``jax.profiler.TraceAnnotation``: a profiler trace then holds a copy on
+the clock of the device's operations.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from dataclasses import dataclass
-from typing import Iterable, List, Optional, Sequence, Tuple
+from typing import (Dict, Iterable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 from .futures import TaskRecord
 
@@ -63,6 +73,8 @@ __all__ = [
     "SUBMIT", "COLD_START", "START", "REQUEUE", "COMPLETE",
     "CAPACITY_GROW", "CAPACITY_SHRINK",
     "WORKER_KILLED", "THROTTLED", "CANCEL", "FOLDED", "CHECKPOINT",
+    "SpanRecord", "span", "count", "current", "enable", "disable",
+    "enabled", "collect",
 ]
 
 SUBMIT = "submit"
@@ -369,3 +381,189 @@ class EventLog:
 
         return heapq.merge(*(stream(log) for log in logs),
                            key=lambda e: e.t)
+
+
+# -- spans and counters inside the program -------------------------------
+#
+# Off (the default), ``span`` returns one shared no-op context and
+# ``count`` returns at once: one flag check, no allocation, no clock
+# read, no lock.  On, every span is recorded with its parent: within a
+# thread the innermost open span, across threads the context a pool's
+# ``submit`` captured on the task (``current()``).  Spans of one tree
+# carry the number of its root span, so every span of one
+# ``run_irregular`` job carries the number of its ``master.job``.  No
+# span is named ``job`` or starts with ``task.``: a benchmark may keep
+# those names for spans of its own.
+
+
+class SpanRecord(NamedTuple):
+    """One closed span; times on ``time.monotonic``, in seconds."""
+
+    name: str
+    start: float
+    end: float
+    span_id: int
+    #: the span that caused this one, ``None`` for a root
+    parent: Optional[int]
+    #: the number of the root span of this span's tree
+    job: int
+    #: the name of the thread that ran it
+    thread: str
+    spec: Optional[str] = None
+    task: Optional[int] = None
+
+
+class _NoSpan:
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, exc_type: object, exc: object, tb: object) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+_on = False
+_annotation = None  # jax.profiler.TraceAnnotation, bound by enable()
+_span_ids = itertools.count(1)
+_job_ids = itertools.count(1)
+# Each thread records into its own state, so that a span or a count
+# takes no lock that eight busy worker threads would contend for.
+_local = threading.local()
+_threads_lock = threading.Lock()
+_threads: List["_ThreadState"] = []
+
+
+class _ThreadState:
+    __slots__ = ("thread", "stack", "spans", "counters")
+
+    def __init__(self) -> None:
+        self.thread = threading.current_thread()
+        #: open spans, innermost last
+        self.stack: List[_Span] = []
+        #: closed spans, as ``SpanRecord`` fields
+        self.spans: List[tuple] = []
+        self.counters: Dict[str, int] = {}
+
+
+def _state() -> _ThreadState:
+    st = getattr(_local, "state", None)
+    if st is None:
+        st = _local.state = _ThreadState()
+        with _threads_lock:
+            _threads.append(st)
+    return st
+
+
+class _Span:
+    __slots__ = ("name", "spec", "task", "link", "state", "span_id", "job",
+                 "parent", "start", "annotation")
+
+    def __init__(self, name: str, spec: Optional[str], task: Optional[int],
+                 link: Optional[Tuple[int, int]]) -> None:
+        self.name = name
+        self.spec = spec
+        self.task = task
+        self.link = link
+
+    # The span's own bookkeeping falls inside its interval, so a phase's
+    # time includes what recording it cost (with eight threads, a wait
+    # for the interpreter lock can land there), and its parent's time is
+    # not left unaccounted for.
+    def __enter__(self) -> "_Span":
+        self.start = time.monotonic()
+        st = self.state = _state()
+        self.span_id = next(_span_ids)
+        if self.link is not None:
+            self.parent, self.job = self.link
+        elif st.stack:
+            top = st.stack[-1]
+            self.parent, self.job = top.span_id, top.job
+        else:
+            self.parent, self.job = None, next(_job_ids)
+        st.stack.append(self)
+        self.annotation = _annotation(self.name)
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type: object, exc: object, tb: object) -> None:
+        self.annotation.__exit__(None, None, None)
+        st = self.state
+        st.stack.pop()
+        st.spans.append((self.name, self.start, time.monotonic(),
+                         self.span_id, self.parent, self.job, st.thread.name,
+                         self.spec, self.task))
+
+
+def span(name: str, spec: Optional[str] = None, task: Optional[int] = None,
+         parent: Optional[Tuple[int, int]] = None):
+    """A context manager that records ``name`` from enter to exit.
+
+    ``spec`` and ``task`` are attributes to keep (a job's spec name, a
+    task id).  ``parent`` is a context that :func:`current` captured on
+    another thread; without it the parent is this thread's innermost
+    open span."""
+    if not _on:
+        return _NO_SPAN
+    return _Span(name, spec, task, parent)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name`` (each thread keeps its own sums;
+    :func:`collect` adds them up)."""
+    if not _on:
+        return
+    counters = _state().counters
+    counters[name] = counters.get(name, 0) + int(n)
+
+
+def current() -> Optional[Tuple[int, int]]:
+    """``(span id, job number)`` of this thread's innermost open span, to
+    hand to work that another thread runs; ``None`` when off or when no
+    span is open."""
+    if not _on:
+        return None
+    st = getattr(_local, "state", None)
+    if st is None or not st.stack:
+        return None
+    top = st.stack[-1]
+    return (top.span_id, top.job)
+
+
+def enabled() -> bool:
+    return _on
+
+
+def enable() -> None:
+    """Start recording spans and counters."""
+    global _on, _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    _on = True
+
+
+def disable() -> None:
+    """Stop recording; what was recorded stays until :func:`collect`."""
+    global _on
+    _on = False
+
+
+def collect() -> Tuple[List[SpanRecord], Dict[str, int]]:
+    """The spans closed and the counters summed since the last collect,
+    and forget them.  Call it after :func:`disable`: what a thread
+    records while the collect runs may be lost."""
+    spans: List[SpanRecord] = []
+    counters: Dict[str, int] = {}
+    with _threads_lock:
+        for st in _threads:
+            taken, st.spans = st.spans, []
+            sums, st.counters = st.counters, {}
+            spans.extend(SpanRecord._make(t) for t in taken)
+            for name, n in sums.items():
+                counters[name] = counters.get(name, 0) + n
+        # a thread that has ended records nothing more
+        _threads[:] = [st for st in _threads if st.thread.is_alive()]
+    spans.sort(key=lambda r: r.span_id)
+    return spans, counters

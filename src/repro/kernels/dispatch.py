@@ -28,7 +28,16 @@ there) and interpret-mode plumbing.  This module centralizes all of it:
     (op, backend, static-kwargs) triple, reused across all bucketed
     shapes, with a :func:`compile_log` the tests use to assert the
     O(log) bound;
-  - **unpadding**: outputs are sliced back to the caller's true sizes.
+  - **unpadding**: outputs are sliced back to the caller's true sizes;
+  - **accounting**: with the recorder of ``repro.core.telemetry`` on,
+    each call runs inside a ``dispatch.<op>`` span and adds the output
+    positions the kernel ran on, padding included, to
+    ``<op>.launched`` and those the caller keeps to ``<op>.kept``.
+
+:func:`to_device` and :func:`to_host` are ``jnp.asarray`` and
+``np.asarray`` across the host/device boundary, counted in
+``h2d_bytes`` and ``d2h_bytes``; the copy to the host, which waits for
+the device, runs in a ``device.pull`` span.
 
 The three shipped ops — ``uts_hash``, ``mandelbrot``,
 ``flash_attention_fwd`` — are registered by their packages'
@@ -36,6 +45,7 @@ The three shipped ops — ``uts_hash``, ``mandelbrot``,
 """
 from __future__ import annotations
 
+import math
 import os
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -43,12 +53,15 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ..core.telemetry import count, enabled, span
 
 __all__ = [
     "KernelOp", "register_kernel", "get_kernel", "registered_kernels",
     "dispatch", "bucket", "resolve_backend", "on_tpu",
     "compile_log", "reset_compile_log", "estimate_cost",
-    "enable_compile_cache",
+    "enable_compile_cache", "to_device", "to_host",
 ]
 
 #: canonical backend names, in resolution-priority order
@@ -139,6 +152,8 @@ _JIT_CACHE: Dict[Tuple[str, str, tuple], Callable[..., Any]] = {}
 # not grow this diagnostic set forever.
 _COMPILE_LOG: Dict[str, Set[tuple]] = {}
 _COMPILE_LOG_CAP = 4096
+# op name -> (span, launched counter, kept counter), built once per op
+_TELEMETRY_NAMES: Dict[str, Tuple[str, str, str]] = {}
 
 
 def register_kernel(op: KernelOp) -> KernelOp:
@@ -240,65 +255,105 @@ def _jitted(op: KernelOp, backend: str,
             interpret = backend == "interpret"
             def call(*arrays: Any) -> Any:
                 return op.pallas_body(*arrays, interpret=interpret, **skw)
+        # the jitted program, and so the profiler's host and device
+        # events, carry the op's name
+        call.__name__ = call.__qualname__ = op.name
         fn = jax.jit(call)
         _JIT_CACHE[key] = fn
     return fn
 
 
+def _telemetry_names(op: KernelOp) -> Tuple[str, str, str]:
+    names = _TELEMETRY_NAMES.get(op.name)
+    if names is None:
+        names = _TELEMETRY_NAMES[op.name] = (
+            f"dispatch.{op.name}", f"{op.name}.launched", f"{op.name}.kept")
+    return names
+
+
 def dispatch(op: Union[str, KernelOp], *args: Any,
-             backend: Optional[str] = None, **static: Any) -> Any:
+             backend: Optional[str] = None, kept: Optional[int] = None,
+             **static: Any) -> Any:
     """Run a registered kernel: pad -> jit-dispatch -> unpad.
 
     ``static`` kwargs (iteration counts, block shapes, masks flags...)
     are forwarded to the op bodies and must be hashable — they are part
     of the jit-cache key alongside the op, the backend, and the
-    bucketed operand shapes.
+    bucketed operand shapes.  ``kept`` is how many of the output's
+    positions the caller uses, where it padded the operands itself
+    (default: all it passed).
     """
     if isinstance(op, str):
         op = get_kernel(op)
-    backend = resolve_backend(backend)
+    span_name, launched_name, kept_name = _telemetry_names(op)
 
-    # -- measure the elastic dims off the unpadded operands ---------------
-    dims: Dict[str, int] = {}
-    for i, (arr, adims) in enumerate(zip(args, op.arg_dims)):
-        for axis, dname in adims:
-            size = arr.shape[axis]
-            if dims.setdefault(dname, size) != size:
-                raise ValueError(
-                    f"{op.name}: dim {dname!r} is {dims[dname]} but arg "
-                    f"{i} axis {axis} has size {size}")
+    with span(span_name):
+        backend = resolve_backend(backend)
+        # -- measure the elastic dims off the unpadded operands -----------
+        dims: Dict[str, int] = {}
+        for i, (arr, adims) in enumerate(zip(args, op.arg_dims)):
+            for axis, dname in adims:
+                size = arr.shape[axis]
+                if dims.setdefault(dname, size) != size:
+                    raise ValueError(
+                        f"{op.name}: dim {dname!r} is {dims[dname]} but "
+                        f"arg {i} axis {axis} has size {size}")
 
-    buckets = {d: bucket(n, op.bucket_floor) for d, n in dims.items()}
+        buckets = {d: bucket(n, op.bucket_floor) for d, n in dims.items()}
 
-    # -- pad every elastic axis up to its bucket ---------------------------
-    padded = []
-    for i, arr in enumerate(args):
-        adims = op.arg_dims[i] if i < len(op.arg_dims) else ()
-        widths = [(0, 0)] * getattr(arr, "ndim", 0)
-        grew = False
-        for axis, dname in adims:
-            extra = buckets[dname] - arr.shape[axis]
-            if extra:
-                widths[axis] = (0, extra)
-                grew = True
-        if grew:
-            pv = op.pad_values[i] if i < len(op.pad_values) else 0
-            arr = jnp.pad(arr, widths, constant_values=pv)
-        padded.append(arr)
+        # -- pad every elastic axis up to its bucket -----------------------
+        padded = []
+        for i, arr in enumerate(args):
+            adims = op.arg_dims[i] if i < len(op.arg_dims) else ()
+            widths = [(0, 0)] * getattr(arr, "ndim", 0)
+            grew = False
+            for axis, dname in adims:
+                extra = buckets[dname] - arr.shape[axis]
+                if extra:
+                    widths[axis] = (0, extra)
+                    grew = True
+            if grew:
+                pv = op.pad_values[i] if i < len(op.pad_values) else 0
+                arr = jnp.pad(arr, widths, constant_values=pv)
+            padded.append(arr)
 
-    skey = tuple(sorted(static.items()))
-    sig = tuple((tuple(a.shape), str(a.dtype))
-                if hasattr(a, "shape") else repr(a) for a in padded)
-    log = _COMPILE_LOG.setdefault(op.name, set())
-    if len(log) < _COMPILE_LOG_CAP:
-        log.add((backend, skey, sig))
+        skey = tuple(sorted(static.items()))
+        sig = tuple((tuple(a.shape), str(a.dtype))
+                    if hasattr(a, "shape") else repr(a) for a in padded)
+        log = _COMPILE_LOG.setdefault(op.name, set())
+        if len(log) < _COMPILE_LOG_CAP:
+            log.add((backend, skey, sig))
 
-    out = _jitted(op, backend, skey)(*padded)
+        out = _jitted(op, backend, skey)(*padded)
 
-    # -- slice the padding back off ---------------------------------------
-    if op.out_dims:
-        index: List[Any] = [slice(None)] * out.ndim
-        for axis, dname in op.out_dims:
-            index[axis] = slice(0, dims[dname])
-        out = out[tuple(index)]
+        # -- slice the padding back off -----------------------------------
+        if op.out_dims:
+            index: List[Any] = [slice(None)] * out.ndim
+            for axis, dname in op.out_dims:
+                index[axis] = slice(0, dims[dname])
+            out = out[tuple(index)]
+    if enabled() and op.out_dims:
+        count(launched_name,
+              math.prod(buckets[d] for _, d in op.out_dims))
+        count(kept_name, kept if kept is not None
+              else math.prod(dims[d] for _, d in op.out_dims))
+    return out
+
+
+def to_device(x: Any, dtype: Any = None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``: a host array onto the default device,
+    its bytes counted in ``h2d_bytes``."""
+    out = jnp.asarray(x, dtype)
+    if enabled():
+        count("h2d_bytes", out.nbytes)
+    return out
+
+
+def to_host(x: jax.Array) -> np.ndarray:
+    """``np.asarray(x)``: wait for the device, copy the array to the host,
+    its bytes counted in ``d2h_bytes``."""
+    with span("device.pull"):
+        out = np.asarray(x)
+    if enabled():
+        count("d2h_bytes", out.nbytes)
     return out

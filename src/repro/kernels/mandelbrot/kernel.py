@@ -80,4 +80,5 @@ def mandelbrot_pallas(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.int32),
         interpret=interpret,
+        name="mandelbrot",
     )(c_re.astype(jnp.float32), c_im.astype(jnp.float32))

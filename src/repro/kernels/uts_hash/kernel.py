@@ -103,4 +103,5 @@ def uts_hash_pallas(parent: jax.Array, child_ix: jax.Array, *,
         out_specs=pl.BlockSpec((5, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((5, n), jnp.uint32),
         interpret=interpret,
+        name="uts_hash",
     )(parent.astype(jnp.uint32), child_ix.reshape(1, -1).astype(jnp.uint32))

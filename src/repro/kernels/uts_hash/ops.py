@@ -58,17 +58,20 @@ register_kernel(KernelOp(
 
 def uts_child_digests(parent: jax.Array, child_ix: jax.Array, *,
                       block_n: int = DEFAULT_BLOCK_N,
-                      backend: str | None = None) -> jax.Array:
+                      backend: str | None = None,
+                      kept: int | None = None) -> jax.Array:
     """SHA1(parent || be32(ix)) for [5, N] parents, [N] indices.
 
     backend: "tpu-pallas" (compiled Mosaic, TPU), "interpret" (Pallas
     interpreter — used by the kernel test sweeps), "ref" (pure-jnp oracle
     — the fast path on CPU, bit-identical by test), or None = auto.
+    kept: how many of the N digests the caller uses, where it padded
+    the operands itself (``dispatch``'s ``uts_hash.kept`` counter).
     """
     if parent.shape[1] == 0:
         return jnp.zeros((5, 0), jnp.uint32)
     return dispatch("uts_hash", parent, child_ix, backend=backend,
-                    block_n=block_n)
+                    kept=kept, block_n=block_n)
 
 
 def root_digest(seed: int) -> jax.Array:

@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.algorithms.betweenness import bc_batch
-from repro.kernels.dispatch import get_kernel
+from repro.kernels.dispatch import _jitted, get_kernel
 from repro.kernels.uts_hash.ops import geometric_children
 
 
@@ -80,3 +80,19 @@ def test_bc_batch_compiles_without_bf16(one_chip):
     # path counts sigma must not pass through a one-pass bf16 product:
     # no array of the program is bf16
     assert "bf16[" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("op, shape, dtype, static", [
+    ("uts_hash", ((5, 16384), (16384,)), jnp.uint32, ()),
+    ("mandelbrot", ((8, 256), (8, 256)), jnp.float32,
+     (("max_iter", 4096),))])
+def test_dispatched_kernel_carries_its_name(one_chip, op, shape, dtype,
+                                            static):
+    # the jitted program and the kernel's instruction are named after the
+    # op, so the profiler's host and device events say which kernel ran
+    fn = _jitted(get_kernel(op), "tpu-pallas", static)
+    lowered = fn.lower(*(_spec(s, dtype, one_chip) for s in shape))
+    assert lowered.as_text().startswith(f"module @jit_{op} ")
+    [line] = [ln for ln in lowered.compile().as_text().splitlines()
+              if "tpu_custom_call" in ln]
+    assert f"%{op}.1 = " in line

@@ -39,13 +39,10 @@ from ..core import (
 )
 from ..core.telemetry import span
 from ..kernels.dispatch import bucket, on_tpu, to_device, to_host
-from ..kernels.uts_hash.ops import (
-    geometric_children,
-    root_digest,
-    uts_child_digests,
-)
+from ..kernels.uts_hash.ops import geometric_children, uts_child_digests
 from ..kernels.uts_hash.numpy_impl import (
     geometric_children_np,
+    root_digest,
     uts_child_digests_np,
 )
 
@@ -80,8 +77,7 @@ class Bag:
     @staticmethod
     def root(params: UTSParams) -> "Bag":
         with span("uts.root"):
-            d = to_host(root_digest(params.seed))
-            return Bag(d, np.zeros((1,), np.int32))
+            return Bag(root_digest(params.seed), np.zeros((1,), np.int32))
 
     def split(self, k: int) -> List["Bag"]:
         """Resize into <= k sub-bags (paper's ``resizeBag``)."""

@@ -5,15 +5,18 @@ hosts the worker — on a pod that is the TPU (Pallas kernel); in this
 container it is a single CPU core, where vectorized numpy beats the XLA
 CPU emulation of the kernel by ~2 orders of magnitude.  Bit-identical to
 ref.py / kernel.py (asserted in the test suite), so backends are
-interchangeable.
+interchangeable.  The root digest is one 24-byte message per job, so it
+is hashed here with ``hashlib`` and never becomes a device program.
 """
 from __future__ import annotations
 
+import hashlib
 import math
+import operator
 
 import numpy as np
 
-__all__ = ["uts_child_digests_np", "geometric_children_np",
+__all__ = ["root_digest", "uts_child_digests_np", "geometric_children_np",
            "geometric_thresholds"]
 
 _H0 = (0x67452301, 0xEFCDAB89, 0x98BADCFE, 0x10325476, 0xC3D2E1F0)
@@ -64,6 +67,20 @@ def uts_child_digests_np(parent: np.ndarray, child_ix: np.ndarray) -> np.ndarray
         ])
     finally:
         np.seterr(**old)
+
+
+def root_digest(seed: int) -> np.ndarray:
+    """Root node state: SHA1(zero_digest || be32(seed)) -> [5, 1] uint32.
+
+    Canonical UTS seeds the root by hashing the seed into a zero state.
+    The words are big-endian, word-major: the layout of ``Bag.digests``.
+    A seed outside [0, 2**32) has no be32 encoding and raises ValueError.
+    """
+    seed = operator.index(seed)
+    if not 0 <= seed < 2**32:
+        raise ValueError(f"UTS root seed {seed} is outside [0, 2**32)")
+    dig = hashlib.sha1(bytes(20) + seed.to_bytes(4, "big")).digest()
+    return np.frombuffer(dig, ">u4").astype(np.uint32).reshape(5, 1)
 
 
 def geometric_thresholds(b0: float, max_children: int) -> np.ndarray:

@@ -26,7 +26,7 @@ from .ref import uts_child_digests_ref
 
 __all__ = [
     "uts_child_digests", "uts_child_digests_ref",
-    "root_digest", "random_u31", "geometric_children",
+    "random_u31", "geometric_children",
 ]
 
 
@@ -72,16 +72,6 @@ def uts_child_digests(parent: jax.Array, child_ix: jax.Array, *,
         return jnp.zeros((5, 0), jnp.uint32)
     return dispatch("uts_hash", parent, child_ix, backend=backend,
                     kept=kept, block_n=block_n)
-
-
-def root_digest(seed: int) -> jax.Array:
-    """Root node state: SHA1(zero_digest || be32(seed)) — [5, 1] uint32.
-
-    Canonical UTS seeds the root by hashing the seed into a zero state.
-    """
-    zero = jnp.zeros((5, 1), jnp.uint32)
-    ix = jnp.array([seed], jnp.uint32)
-    return uts_child_digests_ref(zero, ix)
 
 
 def random_u31(digest: jax.Array) -> jax.Array:

@@ -9,8 +9,9 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.mandelbrot.ops import mandelbrot, mandelbrot_rect
 from repro.kernels.mandelbrot.ref import coords, mandelbrot_ref
-from repro.kernels.uts_hash.numpy_impl import uts_child_digests_np
-from repro.kernels.uts_hash.ops import root_digest, uts_child_digests
+from repro.kernels.uts_hash.numpy_impl import (root_digest,
+                                               uts_child_digests_np)
+from repro.kernels.uts_hash.ops import uts_child_digests
 from repro.kernels.uts_hash.ref import uts_child_digests_ref
 
 
@@ -90,11 +91,31 @@ def test_uts_hash_property_vs_hashlib(word0, ix):
 
 
 def test_root_digest_deterministic():
-    a = np.asarray(root_digest(19))
-    b = np.asarray(root_digest(19))
-    c = np.asarray(root_digest(42))
+    a = root_digest(19)
+    b = root_digest(19)
+    c = root_digest(42)
     assert np.array_equal(a, b)
     assert not np.array_equal(a, c)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 19, 42, 2**31, 2**32 - 1])
+def test_root_digest_matches_device_oracle(seed):
+    """The host root is the zero state's child ``seed`` under the jnp
+    oracle and the numpy twin, bit for bit, in Bag's [5, 1] layout."""
+    got = root_digest(seed)
+    assert got.shape == (5, 1) and got.dtype == np.uint32
+    zero = np.zeros((5, 1), np.uint32)
+    ix = np.array([seed], np.uint32)
+    assert np.array_equal(got, np.asarray(uts_child_digests_ref(
+        jnp.asarray(zero), jnp.asarray(ix))))
+    assert np.array_equal(got, uts_child_digests_np(zero, ix))
+    assert np.array_equal(got, _hashlib_oracle(zero, ix))
+
+
+@pytest.mark.parametrize("seed", [-1, 2**32, 2**40])
+def test_root_digest_rejects_seeds_outside_u32(seed):
+    with pytest.raises(ValueError):
+        root_digest(seed)
 
 
 def test_uts_hash_block_invariance():

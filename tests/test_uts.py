@@ -1,15 +1,16 @@
 """UTS correctness: hash oracle, determinism, parallel == sequential."""
 import hashlib
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.algorithms.uts import (Bag, UTSParams, expand_bag,
                                   expected_tree_size, uts_parallel,
-                                  uts_sequential)
+                                  uts_sequential, uts_spec)
 from repro.core import ElasticExecutor, LocalExecutor, StagedController, \
-    TaskShape
+    TaskShape, make_pool, run_irregular
 from repro.kernels.uts_hash.numpy_impl import (geometric_children_np,
                                                uts_child_digests_np)
 
@@ -144,3 +145,33 @@ def test_bag_split_merge_roundtrip():
     a = np.sort(bag.digests[0])
     b = np.sort(merged.digests[0])
     assert np.array_equal(a, b)
+
+
+LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+def test_new_roots_lower_no_program():
+    """Seeding a job is a host hash: neither a new root nor a second
+    job on a new root lowers a program (each lowering was a serial
+    stall before the job's first task)."""
+    lowered = []
+
+    def on_event(event, duration, **kw):
+        if event == LOWERED_EVENT:
+            lowered.append(event)
+
+    params = [UTSParams(seed=s, b0=4.0, max_depth=5, chunk=512)
+              for s in (3_000_000_019, 3_000_000_029, 3_000_000_031)]
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for p in params[:2]:
+            assert Bag.root(p).size == 1
+        assert lowered == []
+        with make_pool("local", max_concurrency=2) as pool:
+            first = run_irregular(pool, uts_spec(params[1]))
+            second = run_irregular(pool, uts_spec(params[2]))
+        assert lowered == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert first.output == uts_sequential(params[1])
+    assert second.output == uts_sequential(params[2])

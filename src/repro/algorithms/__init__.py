@@ -29,6 +29,7 @@ from .betweenness import (
     BCResult,
     RMATParams,
     bc_batch,
+    bc_reference,
     bc_single_node,
     bc_spec,
     betweenness_centrality,
@@ -40,6 +41,6 @@ __all__ = [
     "uts_parallel", "uts_sequential", "uts_spec",
     "Action", "MSParams", "MSResult", "Rect", "evaluate_rect",
     "mariani_silver", "ms_spec", "naive_render", "plane_coords",
-    "BCResult", "RMATParams", "bc_batch", "bc_single_node", "bc_spec",
-    "betweenness_centrality", "rmat_graph",
+    "BCResult", "RMATParams", "bc_batch", "bc_reference", "bc_single_node",
+    "bc_spec", "betweenness_centrality", "rmat_graph",
 ]

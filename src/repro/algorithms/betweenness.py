@@ -13,6 +13,8 @@ queue-based X10/Java loops.  Each task re-generates the graph locally
 (paper Listing 4 line 44: the graph is too large to ship to a function,
 so functions rebuild it from the R-MAT parameters) — kept here behind
 ``regenerate_graph`` to reproduce the shared-resources experiment.
+
+``bc_reference`` is the plain float64 Brandes the pool is tested against.
 """
 from __future__ import annotations
 
@@ -27,14 +29,19 @@ import jax
 import jax.numpy as jnp
 
 from ..core import Pool, TaskShape, WorkSpec, run_irregular
+from ..core.telemetry import count, enabled, span
+from ..kernels.dispatch import to_device, to_host
 
-__all__ = ["RMATParams", "rmat_graph", "bc_batch", "bc_single_node",
-           "bc_spec", "betweenness_centrality", "BCResult"]
+__all__ = ["RMATParams", "rmat_graph", "bc_batch", "bc_reference",
+           "bc_single_node", "bc_spec", "betweenness_centrality",
+           "BCResult"]
 
 _INF = np.int32(2**30)
 # f32 products at full precision: at default precision the TPU multiplies
 # in one bf16 pass, which rounds path counts sigma above 256
 _EXACT = jax.lax.Precision.HIGHEST
+#: sources per level-synchronous pass of ``bc_reference``
+_REF_BLOCK = 256
 
 
 @dataclass(frozen=True)
@@ -85,11 +92,15 @@ def rmat_graph(p: RMATParams, permute: bool = True) -> np.ndarray:
 @functools.partial(jax.jit, static_argnames=("max_levels",))
 def bc_batch(adj: jax.Array, sources: jax.Array,
              max_levels: Optional[int] = None) -> jax.Array:
-    """Brandes dependency sums for a batch of sources -> [N] partial BC.
+    """Brandes dependency sums for a batch of sources, and the levels swept.
 
     adj:     [N, N] float32 dense adjacency (directed, unweighted)
     sources: [S] int32 source vertex ids
-    returns  [N] float32 — sum over the batch of dependency scores delta.
+    returns  [N + 1] float32 — the sum over the batch of the dependency
+             scores delta of each vertex, then the number of BFS levels
+             the forward sweep expanded (levels 0 to the batch's depth;
+             at most N, so exact in float32).  The two share one array
+             so that one transfer brings both to the host.
     """
     n = adj.shape[0]
     s = sources.shape[0]
@@ -138,27 +149,96 @@ def bc_batch(adj: jax.Array, sources: jax.Array,
 
     # exclude the source itself from its own dependency sum
     delta = delta * (1.0 - src_onehot)
-    return delta.sum(axis=0)
+    return jnp.concatenate([delta.sum(axis=0),
+                            level[None].astype(jnp.float32)])
+
+
+def bc_reference(adj: np.ndarray) -> np.ndarray:
+    """Betweenness of every vertex by Brandes' algorithm in float64 numpy.
+
+    SSCA#2 kernel 4 (Bader & Madduri): for each vertex v, the sum over
+    sources s != v of the dependency delta_s(v) = sum over targets t of
+    sigma_st(v) / sigma_st, with delta_s accumulated back from the
+    farthest BFS level (Brandes 2001).  Departures from the kernel's
+    definition, which the program shares:
+
+    * directed: paths follow ``adj[u, v] != 0`` from u to v, and no
+      score is halved;
+    * unweighted: no edge is filtered by its weight, every edge counts
+      as length 1 (duplicate R-MAT samples are one edge, self-loops are
+      dropped by ``rmat_graph``);
+    * exact: every vertex is a source (no sampling of K4approx sources);
+    * a source is excluded from its own dependency: delta_s(s) is 0.
+
+    Sources are taken 256 at a time, level-synchronously: one product
+    with the adjacency expands the frontiers of all of them.
+    """
+    a = (np.asarray(adj) != 0).astype(np.float64)
+    n = a.shape[0]
+    out = np.zeros(n, np.float64)
+    for start in range(0, n, _REF_BLOCK):
+        src = np.arange(start, min(n, start + _REF_BLOCK))
+        rows = np.arange(src.size)
+        dist = np.full((src.size, n), -1, np.int64)
+        sigma = np.zeros((src.size, n), np.float64)
+        dist[rows, src] = 0
+        sigma[rows, src] = 1.0
+        depth = 0
+        while True:
+            reach = (sigma * (dist == depth)) @ a
+            new = (dist < 0) & (reach > 0)
+            if not new.any():
+                break
+            depth += 1
+            dist[new] = depth
+            sigma[new] = reach[new]
+        delta = np.zeros_like(sigma)
+        safe_sigma = np.where(sigma > 0, sigma, 1.0)
+        for lvl in range(depth, 0, -1):
+            coeff = np.where(dist == lvl, (1.0 + delta) / safe_sigma, 0.0)
+            delta += np.where(dist == lvl - 1, sigma * (coeff @ a.T), 0.0)
+        delta[rows, src] = 0.0
+        out += delta.sum(axis=0)
+    return out
 
 
 def bc_single_node(adj: np.ndarray, n_tasks: int = 1) -> np.ndarray:
-    """All-sources BC on the host (reference / 'parallel VM' baseline)."""
+    """All-sources BC through ``bc_batch`` on one device, without the
+    pool ('parallel VM' baseline); the plain reference is
+    ``bc_reference``."""
     n = adj.shape[0]
     adj_j = jnp.asarray(adj)
     out = np.zeros(n, np.float64)
     for block in np.array_split(np.arange(n, dtype=np.int32),
                                 max(1, n_tasks)):
-        out += np.asarray(bc_batch(adj_j, jnp.asarray(block)), np.float64)
+        out += np.asarray(bc_batch(adj_j, jnp.asarray(block)),
+                          np.float64)[:n]
     return out
 
 
 def _bc_task(p: RMATParams, sources: np.ndarray,
              adj: Optional[np.ndarray]) -> np.ndarray:
-    """Task body (``ServerlessCallable`` of Listing 4)."""
+    """Task body (``ServerlessCallable`` of Listing 4).
+
+    With the recorder of ``repro.core.telemetry`` on: spans ``bc.graph``
+    (the rebuild), ``bc.upload`` (adjacency and sources to the device),
+    ``dispatch.bc_batch`` (the sweeps' dispatch) and ``device.pull``;
+    counters ``bc.sources`` and ``bc.levels`` (BFS levels the forward
+    sweep expanded), beside ``h2d_bytes`` and ``d2h_bytes``."""
     if adj is None:
-        adj = rmat_graph(p)  # line 44: generateGraph() inside the function
-    return np.asarray(bc_batch(jnp.asarray(adj),
-                               jnp.asarray(sources.astype(np.int32))))
+        with span("bc.graph"):
+            # line 44: generateGraph() inside the function
+            adj = rmat_graph(p)
+    with span("bc.upload"):
+        adj_d = to_device(adj)
+        sources_d = to_device(sources, np.int32)
+    with span("dispatch.bc_batch"):
+        out = bc_batch(adj_d, sources_d)
+    out = to_host(out)
+    if enabled():
+        count("bc.sources", sources.size)
+        count("bc.levels", int(out[-1]))
+    return out[:-1]
 
 
 @dataclass

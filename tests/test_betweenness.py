@@ -1,12 +1,14 @@
-"""Betweenness Centrality vs networkx (exact Brandes oracle)."""
+"""Betweenness Centrality vs networkx (exact Brandes oracle) and vs the
+plain float64 reference ``bc_reference``."""
 import networkx as nx
 import numpy as np
 import pytest
 
-from repro.algorithms.betweenness import (RMATParams, bc_single_node,
+from repro.algorithms.betweenness import (RMATParams, bc_reference,
+                                          bc_single_node, bc_spec,
                                           betweenness_centrality,
                                           rmat_graph)
-from repro.core import ElasticExecutor, LocalExecutor
+from repro.core import ElasticExecutor, LocalExecutor, make_pool, run_irregular
 
 
 def _nx_bc(adj):
@@ -61,3 +63,25 @@ def test_rmat_deterministic():
     a = rmat_graph(RMATParams(scale=6, seed=2))
     b = rmat_graph(RMATParams(scale=6, seed=2))
     assert np.array_equal(a, b)
+
+
+def test_reference_matches_networkx():
+    adj = rmat_graph(RMATParams(scale=6, seed=2))
+    np.testing.assert_allclose(bc_reference(adj), _nx_bc(adj), rtol=1e-12,
+                               atol=1e-9)
+
+
+@pytest.mark.parametrize("regenerate", [True, False],
+                         ids=["regenerated", "shipped"])
+@pytest.mark.parametrize("seed", [2, 3, 11])
+@pytest.mark.parametrize("scale", [6, 7, 8])
+def test_pool_matches_reference(scale, seed, regenerate):
+    """The pool's float32 sums against float64 Brandes: the error the
+    benchmark's comparison bounds by 1e-5."""
+    p = RMATParams(scale=scale, seed=seed)
+    with make_pool("local", max_concurrency=4) as pool:
+        res = run_irregular(pool, bc_spec(p, n_tasks=8,
+                                          regenerate_graph=regenerate))
+    ref = bc_reference(rmat_graph(p))
+    assert res.tasks == 8
+    assert np.max(np.abs(res.output - ref) / np.maximum(ref, 1.0)) <= 1e-5

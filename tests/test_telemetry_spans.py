@@ -10,10 +10,12 @@ import threading
 import tracemalloc
 
 import jax
+import networkx as nx
 import numpy as np
 import pytest
 
-from repro.algorithms import MSParams, UTSParams, ms_spec, uts_spec
+from repro.algorithms import (MSParams, RMATParams, UTSParams, bc_spec,
+                              ms_spec, rmat_graph, uts_spec)
 from repro.algorithms import uts as uts_mod
 from repro.core import LocalExecutor, TaskShape, WorkSpec, run_irregular
 from repro.core import telemetry
@@ -162,7 +164,7 @@ def _host_events(trace_dir):
     return names
 
 
-@pytest.mark.parametrize("job", ["uts", "ms"])
+@pytest.mark.parametrize("job", ["uts", "ms", "bc"])
 def test_spans_agree_with_the_profiler_host_trace(job, tmp_path,
                                                   monkeypatch):
     # the task body takes its chip branch (kernels on their reference
@@ -171,6 +173,9 @@ def test_spans_agree_with_the_profiler_host_trace(job, tmp_path,
     if job == "uts":
         spec = uts_spec(UTSParams(seed=19, max_depth=4, chunk=64))
         shape = TaskShape(split_factor=4, iters=40)
+    elif job == "bc":
+        spec = bc_spec(RMATParams(scale=5, seed=2), n_tasks=4)
+        shape = None
     else:
         spec = ms_spec(MSParams(width=32, height=32, max_dwell=32,
                                 max_depth=2, initial_subdivision=2))
@@ -187,10 +192,10 @@ def test_spans_agree_with_the_profiler_host_trace(job, tmp_path,
     spans, counters = telemetry.collect()
     names = {s.name for s in spans}
     assert not any(n == "job" or n.startswith("task.") for n in names)
-    phases = ({"uts.root", "uts.frontier", "uts.merge",
-               "dispatch.geometric_children", "dispatch.uts_hash"}
-              if job == "uts" else
-              {"ms.coords", "ms.classify", "dispatch.mandelbrot"})
+    phases = {"uts": {"uts.root", "uts.frontier", "uts.merge",
+                      "dispatch.geometric_children", "dispatch.uts_hash"},
+              "ms": {"ms.coords", "ms.classify", "dispatch.mandelbrot"},
+              "bc": {"bc.graph", "bc.upload", "dispatch.bc_batch"}}[job]
     assert phases | {"device.pull", "pool.task", "master.job"} <= names
     assert counters["h2d_bytes"] > 0 and counters["d2h_bytes"] > 0
     host = _host_events(tmp_path)
@@ -242,6 +247,40 @@ def test_hand_sized_generation_counts_pad_and_bytes(monkeypatch):
     # indices; down: the child counts, then each slice's digests
     assert counters["h2d_bytes"] == 6 * 4 * nb + slices * 6 * 4 * hb
     assert counters["d2h_bytes"] == 4 * nb + slices * 5 * 4 * hb
+
+
+def test_bc_tasks_record_their_phases_and_counts():
+    """Each BC task: its rebuild, upload, sweeps and pull as spans
+    inside its ``pool.task``; its sources, its BFS levels (levels 0 to
+    its block's depth, the depth by networkx) and a whole float32
+    adjacency uploaded as counters.  Off, the same job records
+    nothing and gives the same answer."""
+    p = RMATParams(scale=6, seed=2)
+    n, tasks = p.n_vertices, 4
+    spec = bc_spec(p, n_tasks=tasks)
+    with LocalExecutor(4) as pool:
+        off = run_irregular(pool, spec).output
+        assert telemetry.collect() == ([], {})
+        telemetry.enable()
+        on = run_irregular(pool, spec).output
+        telemetry.disable()
+    spans, counters = telemetry.collect()
+    np.testing.assert_array_equal(on, off)
+    by = _by_name(spans)
+    task_ids = {s.span_id for s in by["pool.task"]}
+    for name in ("bc.graph", "bc.upload", "dispatch.bc_batch",
+                 "device.pull"):
+        assert len(by[name]) == tasks, name
+        assert {s.parent for s in by[name]} <= task_ids, name
+    g = nx.from_numpy_array(rmat_graph(p), create_using=nx.DiGraph)
+    levels = [1 + max(max(nx.single_source_shortest_path_length(
+        g, int(s)).values()) for s in block)
+        for block in np.array_split(np.arange(n), tasks)]
+    assert counters["bc.sources"] == n
+    assert counters["bc.levels"] == sum(levels)
+    block = n // tasks
+    assert counters["h2d_bytes"] == tasks * (n * n * 4 + block * 4)
+    assert counters["d2h_bytes"] == tasks * (n + 1) * 4
 
 
 def test_to_device_and_to_host_change_nothing():

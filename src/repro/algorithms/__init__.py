@@ -33,6 +33,8 @@ from .betweenness import (
     bc_single_node,
     bc_spec,
     betweenness_centrality,
+    rmat_adjacency,
+    rmat_edges,
     rmat_graph,
 )
 
@@ -42,5 +44,6 @@ __all__ = [
     "Action", "MSParams", "MSResult", "Rect", "evaluate_rect",
     "mariani_silver", "ms_spec", "naive_render", "plane_coords",
     "BCResult", "RMATParams", "bc_batch", "bc_reference", "bc_single_node",
-    "bc_spec", "betweenness_centrality", "rmat_graph",
+    "bc_spec", "betweenness_centrality", "rmat_adjacency", "rmat_edges",
+    "rmat_graph",
 ]

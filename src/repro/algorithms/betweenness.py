@@ -12,7 +12,11 @@ TPU adaptation: the per-source forward/backward sweeps of Brandes are
 queue-based X10/Java loops.  Each task re-generates the graph locally
 (paper Listing 4 line 44: the graph is too large to ship to a function,
 so functions rebuild it from the R-MAT parameters) — kept here behind
-``regenerate_graph`` to reproduce the shared-resources experiment.
+``regenerate_graph`` to reproduce the shared-resources experiment.  Such
+a task draws the R-MAT edge samples on the host (``rmat_edges``), uploads
+that edge list and scatters it into the dense adjacency on the device
+(``rmat_adjacency``); without ``regenerate_graph`` the master ships the
+dense adjacency that ``rmat_graph`` scatters on the host.
 
 ``bc_reference`` is the plain float64 Brandes the pool is tested against.
 """
@@ -32,7 +36,8 @@ from ..core import Pool, TaskShape, WorkSpec, run_irregular
 from ..core.telemetry import count, enabled, span
 from ..kernels.dispatch import to_device, to_host
 
-__all__ = ["RMATParams", "rmat_graph", "bc_batch", "bc_reference",
+__all__ = ["RMATParams", "rmat_edges", "rmat_graph", "rmat_adjacency",
+           "bc_batch", "bc_reference",
            "bc_single_node", "bc_spec", "betweenness_centrality",
            "BCResult"]
 
@@ -59,34 +64,59 @@ class RMATParams:
         return 1 << self.scale
 
 
-def rmat_graph(p: RMATParams, permute: bool = True) -> np.ndarray:
-    """Dense adjacency (float32 [N, N]) of the R-MAT digraph.
+def rmat_edges(p: RMATParams,
+               permute: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+    """The R-MAT digraph's edge samples: int32 (src, dst), each of length
+    ``edge_factor * N``.
 
-    Recursive-matrix sampling (Chakrabarti et al.), dedup'd, self-loops
-    dropped, vertices permuted (paper §4.1.3: permutation makes the static
-    partition more homogeneous — but still imbalanced).
+    Recursive-matrix sampling (Chakrabarti et al.): one uniform draw per
+    sample and bit level picks a quadrant, the first draw the highest
+    bit; then the vertices are permuted (paper §4.1.3: permutation makes
+    the static partition more homogeneous — but still imbalanced).
+    Duplicates and self-loops stay in the arrays, so their length
+    depends only on the scale; the adjacency keeps a duplicate once and
+    drops a self-loop (``rmat_graph``, ``rmat_adjacency``).
     """
     rng = np.random.RandomState(p.seed)
     n = p.n_vertices
     m = p.edge_factor * n
-    src = np.zeros(m, np.int64)
-    dst = np.zeros(m, np.int64)
-    for _ in range(p.scale):
-        r = rng.rand(m)
-        # quadrant choice per remaining bit
-        q_b = (r >= p.a) & (r < p.a + p.b)
-        q_c = (r >= p.a + p.b) & (r < p.a + p.b + p.c)
-        q_d = r >= p.a + p.b + p.c
-        src = 2 * src + (q_c | q_d)
-        dst = 2 * dst + (q_b | q_d)
-    keep = src != dst
-    src, dst = src[keep], dst[keep]
+    # row k is the k-th bit level's draws, as k successive rand(m) calls
+    r = rng.rand(p.scale, m)
+    ab = p.a + p.b
+    src_bit = r >= ab                                       # quadrant c or d
+    dst_bit = ((r >= p.a) & (r < ab)) | (r >= ab + p.c)     # quadrant b or d
+    weight = (1 << np.arange(p.scale - 1, -1, -1, dtype=np.int32))[:, None]
+    src = (src_bit * weight).sum(axis=0, dtype=np.int32)
+    dst = (dst_bit * weight).sum(axis=0, dtype=np.int32)
     if permute:
-        perm = rng.permutation(n)
+        perm = rng.permutation(n).astype(np.int32)
         src, dst = perm[src], perm[dst]
-    adj = np.zeros((n, n), np.float32)
-    adj[src, dst] = 1.0
+    return src, dst
+
+
+def rmat_graph(p: RMATParams, permute: bool = True) -> np.ndarray:
+    """Dense adjacency (float32 [N, N]) of the R-MAT digraph, scattered
+    on the host from ``rmat_edges``: duplicates kept once, self-loops
+    dropped."""
+    src, dst = rmat_edges(p, permute)
+    keep = src != dst
+    adj = np.zeros((p.n_vertices, p.n_vertices), np.float32)
+    adj[src[keep], dst[keep]] = 1.0
     return adj
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def rmat_adjacency(edges: jax.Array, n: int) -> jax.Array:
+    """``rmat_graph``'s adjacency scattered on the device.
+
+    edges: [2, M] int32, ``rmat_edges``' (src, dst) stacked
+    returns [N, N] float32: 1 at each sample's (src, dst), a self-loop
+            sent to row N and dropped there
+    """
+    src, dst = edges[0], edges[1]
+    src = jnp.where(src == dst, n, src)
+    return jnp.zeros((n, n), jnp.float32).at[src, dst].set(
+        1.0, mode="drop")
 
 
 @functools.partial(jax.jit, static_argnames=("max_levels",))
@@ -216,28 +246,46 @@ def bc_single_node(adj: np.ndarray, n_tasks: int = 1) -> np.ndarray:
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("n",))
+def _bc_batch_from_edges(edges: jax.Array, sources: jax.Array,
+                         n: int) -> jax.Array:
+    """``bc_batch`` over ``rmat_adjacency(edges, n)``, as one program."""
+    return bc_batch(rmat_adjacency(edges, n), sources)
+
+
 def _bc_task(p: RMATParams, sources: np.ndarray,
              adj: Optional[np.ndarray]) -> np.ndarray:
     """Task body (``ServerlessCallable`` of Listing 4).
 
+    Without a shipped ``adj`` the task draws its graph's edge samples,
+    uploads them ([2, edge_factor * N] int32) and scatters them into the
+    adjacency on the device, in the program that runs the sweeps; with
+    one it uploads that dense adjacency.
+
     With the recorder of ``repro.core.telemetry`` on: spans ``bc.graph``
-    (the rebuild), ``bc.upload`` (adjacency and sources to the device),
-    ``dispatch.bc_batch`` (the sweeps' dispatch) and ``device.pull``;
-    counters ``bc.sources`` and ``bc.levels`` (BFS levels the forward
-    sweep expanded), beside ``h2d_bytes`` and ``d2h_bytes``."""
+    (the edge draws), ``bc.upload`` (edges or adjacency, and sources, to
+    the device), ``dispatch.bc_batch`` (the build's and sweeps' dispatch)
+    and ``device.pull``; counters ``bc.sources``, ``bc.levels`` (BFS
+    levels the forward sweep expanded) and ``bc.edges`` (edge samples
+    scattered), beside ``h2d_bytes`` and ``d2h_bytes``."""
     if adj is None:
         with span("bc.graph"):
             # line 44: generateGraph() inside the function
-            adj = rmat_graph(p)
+            graph = np.stack(rmat_edges(p))
+        sweep = functools.partial(_bc_batch_from_edges, n=p.n_vertices)
+    else:
+        graph, sweep = adj, bc_batch
     with span("bc.upload"):
-        adj_d = to_device(adj)
+        graph_d = to_device(graph)
         sources_d = to_device(sources, np.int32)
     with span("dispatch.bc_batch"):
-        out = bc_batch(adj_d, sources_d)
+        out = sweep(graph_d, sources_d)
     out = to_host(out)
     if enabled():
         count("bc.sources", sources.size)
         count("bc.levels", int(out[-1]))
+        if adj is None:
+            count("bc.edges", graph.shape[1])
     return out[:-1]
 
 
@@ -267,11 +315,13 @@ def bc_spec(
     source blocks; each task runs batched Brandes for its block and the
     master aggregates the ``globalBetweennessMap`` (line 34) in the
     ``reduce`` hook.  With ``regenerate_graph`` each function rebuilds
-    the graph from the R-MAT parameters (line 44)."""
-    if adj is None:
-        adj = rmat_graph(p)
-    n = adj.shape[0]
-    shipped = None if regenerate_graph else adj
+    the graph from the R-MAT parameters (line 44) and ``adj`` is unused;
+    without it each function is shipped ``adj``, or ``rmat_graph(p)``
+    built here once."""
+    shipped = None
+    if not regenerate_graph:
+        shipped = rmat_graph(p) if adj is None else adj
+    n = p.n_vertices if shipped is None else shipped.shape[0]
 
     def seed(shape: TaskShape) -> List[np.ndarray]:
         return [block for block in

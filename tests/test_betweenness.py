@@ -7,8 +7,10 @@ import pytest
 from repro.algorithms.betweenness import (RMATParams, bc_reference,
                                           bc_single_node, bc_spec,
                                           betweenness_centrality,
+                                          rmat_adjacency, rmat_edges,
                                           rmat_graph)
 from repro.core import ElasticExecutor, LocalExecutor, make_pool, run_irregular
+from repro.kernels.dispatch import to_device, to_host
 
 
 def _nx_bc(adj):
@@ -63,6 +65,62 @@ def test_rmat_deterministic():
     a = rmat_graph(RMATParams(scale=6, seed=2))
     b = rmat_graph(RMATParams(scale=6, seed=2))
     assert np.array_equal(a, b)
+
+
+def _looped_rmat_graph(p):
+    """The R-MAT generator as first written: one ``rand(m)`` per bit
+    level in a Python loop, self-loops dropped before the permutation,
+    a host scatter.  Kept frozen as the oracle of ``rmat_edges``."""
+    rng = np.random.RandomState(p.seed)
+    n = p.n_vertices
+    m = p.edge_factor * n
+    src = np.zeros(m, np.int64)
+    dst = np.zeros(m, np.int64)
+    for _ in range(p.scale):
+        r = rng.rand(m)
+        q_b = (r >= p.a) & (r < p.a + p.b)
+        q_c = (r >= p.a + p.b) & (r < p.a + p.b + p.c)
+        q_d = r >= p.a + p.b + p.c
+        src = 2 * src + (q_c | q_d)
+        dst = 2 * dst + (q_b | q_d)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    perm = rng.permutation(n)
+    src, dst = perm[src], perm[dst]
+    adj = np.zeros((n, n), np.float32)
+    adj[src, dst] = 1.0
+    return adj
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 7, 123])
+@pytest.mark.parametrize("scale", [4, 6, 8, 10])
+def test_edge_draws_rebuild_the_looped_graph(scale, seed):
+    """``rmat_edges`` draws the looped generator's stream: its host
+    scatter (``rmat_graph``) and its device scatter
+    (``rmat_adjacency``) both give that graph bit for bit."""
+    p = RMATParams(scale=scale, seed=seed)
+    expected = _looped_rmat_graph(p)
+    src, dst = rmat_edges(p)
+    assert src.dtype == dst.dtype == np.int32
+    assert src.shape == dst.shape == (p.edge_factor * p.n_vertices,)
+    np.testing.assert_array_equal(rmat_graph(p), expected)
+    built = rmat_adjacency(to_device(np.stack([src, dst])), p.n_vertices)
+    np.testing.assert_array_equal(to_host(built), expected)
+
+
+@pytest.mark.parametrize("batching", [False, True],
+                         ids=["execute", "execute_batch"])
+def test_regenerated_job_equals_shipped_job(batching):
+    """A task that scatters its own edge draws on the device sweeps the
+    graph a shipped adjacency holds: the same sums, bit for bit."""
+    p = RMATParams(scale=7, seed=2)
+    out = {}
+    with make_pool("local", max_concurrency=4) as pool:
+        for regenerate in (True, False):
+            out[regenerate] = run_irregular(
+                pool, bc_spec(p, n_tasks=8, regenerate_graph=regenerate),
+                batching=batching).output
+    np.testing.assert_array_equal(out[True], out[False])
 
 
 def test_reference_matches_networkx():

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.algorithms.betweenness import bc_batch
+from repro.algorithms.betweenness import _bc_batch_from_edges, bc_batch
 from repro.kernels.dispatch import _jitted, get_kernel
 from repro.kernels.uts_hash.ops import geometric_children
 
@@ -80,6 +80,17 @@ def test_bc_batch_compiles_without_bf16(one_chip):
     # path counts sigma must not pass through a one-pass bf16 product:
     # no array of the program is bf16
     assert "bf16[" not in compiled.as_text()
+
+
+def test_bc_task_program_compiles_without_bf16(one_chip):
+    # a regenerating task's one program: 8 * 4096 edge samples scattered
+    # into the adjacency, then the sweeps of its 32 sources
+    compiled = _bc_batch_from_edges.lower(
+        _spec((2, 8 * 4096), jnp.int32, one_chip),
+        _spec((32,), jnp.int32, one_chip), n=4096).compile()
+    assert "bf16[" not in compiled.as_text()
+    # the adjacency and the sweeps' state, far below one chip's 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
 
 
 @pytest.mark.parametrize("op, shape, dtype, static", [

@@ -250,11 +250,12 @@ def test_hand_sized_generation_counts_pad_and_bytes(monkeypatch):
 
 
 def test_bc_tasks_record_their_phases_and_counts():
-    """Each BC task: its rebuild, upload, sweeps and pull as spans
+    """Each BC task: its edge draws, upload, sweeps and pull as spans
     inside its ``pool.task``; its sources, its BFS levels (levels 0 to
-    its block's depth, the depth by networkx) and a whole float32
-    adjacency uploaded as counters.  Off, the same job records
-    nothing and gives the same answer."""
+    its block's depth, the depth by networkx), its edge samples
+    scattered on the device and their int32 (src, dst) uploaded as
+    counters.  Off, the same job records nothing and gives the same
+    answer."""
     p = RMATParams(scale=6, seed=2)
     n, tasks = p.n_vertices, 4
     spec = bc_spec(p, n_tasks=tasks)
@@ -278,9 +279,31 @@ def test_bc_tasks_record_their_phases_and_counts():
         for block in np.array_split(np.arange(n), tasks)]
     assert counters["bc.sources"] == n
     assert counters["bc.levels"] == sum(levels)
-    block = n // tasks
-    assert counters["h2d_bytes"] == tasks * (n * n * 4 + block * 4)
+    block, m = n // tasks, p.edge_factor * n
+    assert counters["bc.edges"] == tasks * m
+    assert counters["h2d_bytes"] == tasks * (2 * m * 4 + block * 4)
     assert counters["d2h_bytes"] == tasks * (n + 1) * 4
+
+
+def test_bc_job_at_another_graph_seed_lowers_no_program():
+    """A task's programs depend on the scale, not on the graph: a job
+    on another R-MAT seed of the same scale reuses every one."""
+    lowered = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+
+    with LocalExecutor(4) as pool:
+        run_irregular(pool, bc_spec(RMATParams(scale=6, seed=2),
+                                    n_tasks=4))
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            run_irregular(pool, bc_spec(RMATParams(scale=6, seed=11),
+                                        n_tasks=4))
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+    assert lowered == []
 
 
 def test_to_device_and_to_host_change_nothing():
